@@ -24,28 +24,21 @@ def _time(fn, *args, n=3):
 def run() -> list:
     out = []
     # RS encode: 1 MB payload through GF(256) matmul — exp/log numpy vs
-    # product-table numpy vs the two Pallas kernels (ladder vs bit-sliced)
+    # product-table numpy vs the bit-sliced Pallas kernel
     from repro.kernels.rs_gf256.ref import (cauchy_parity_matrix,
                                             gf_matmul_np, gf_matmul_table)
-    from repro.kernels.rs_gf256.kernel import (gf256_matmul_bitsliced,
-                                               gf256_matmul_pallas_ladder)
+    from repro.kernels.rs_gf256.kernel import gf256_matmul_bitsliced
     rng = np.random.default_rng(0)
     k, p, L = 10, 2, 104_858   # ~1MB/10 per chunk
     G = cauchy_parity_matrix(k, p)
     X = rng.integers(0, 256, (k, L)).astype(np.uint8)
     us_np = _time(lambda: gf_matmul_np(G, X))
     us_tab = _time(lambda: gf_matmul_table(G, X))
-    Xj = jnp.asarray(X)
-    us_ld = _time(lambda: np.asarray(
-        gf256_matmul_pallas_ladder(G, Xj, interpret=True)))
-    us_bs = _time(lambda: np.asarray(
-        gf256_matmul_bitsliced(G, Xj, interpret=True)))
+    us_bs = _time(lambda: gf256_matmul_bitsliced(G, X, interpret=True))
     out.append(row("kernel_rs_encode_numpy", us_np,
                    f"bytes={k * L} parity={p} exp/log path"))
     out.append(row("kernel_rs_encode_numpy_table", us_tab,
                    "full 256x256 product table (codec hot path)"))
-    out.append(row("kernel_rs_encode_pallas_ladder", us_ld,
-                   "xtime ladder, byte/lane (CPU interpret)"))
     out.append(row("kernel_rs_encode_pallas_bitsliced", us_bs,
                    "bit-planes, 4 bytes/lane (CPU interpret, TPU target)"))
     # paged attention vs gather fallback

@@ -18,7 +18,10 @@ from pathlib import Path
 from typing import Dict, List, Optional
 
 from repro.configs import SHAPES_BY_NAME, get_config
-from repro.launch.mesh import HW
+from repro.launch.mesh import peaks
+
+# the dry-run compiles for the production mesh of TPU v5e chips
+HW = peaks("TPU v5 lite")
 
 DRYRUN = Path("experiments/dryrun.jsonl")
 

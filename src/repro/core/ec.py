@@ -1,11 +1,13 @@
 """Reed-Solomon erasure-coding codec facade (paper: RS(10+2) by default).
 
 Splits a byte payload into k data chunks + p parity chunks; any k of the
-k+p chunks reconstruct the payload. Host math is numpy via the full
-256x256 product table (one gather + one XOR per coefficient);
-`backend="pallas"` routes the GF(256) matmul through the bit-sliced TPU
-kernel (compiled on TPU, interpret mode on CPU) — bit-identical by
-tests/test_kernels_rs.py.
+k+p chunks reconstruct the payload. The GF(256) matmul runs where the
+process's platform says (`backend="auto"`, the default): the bit-sliced
+Pallas kernel compiled on a TPU, numpy's full 256x256 product table (one
+gather + one XOR per coefficient) everywhere else. `backend="pallas"`
+demands the compiled kernel and raises without a TPU; `"interpret"` runs
+the kernel in the Pallas interpreter; `"numpy"` pins the host table. All
+are bit-identical (tests/test_kernels_rs.py, tests/test_ec.py).
 
 Batched data path: `encode_many` / `decode_many` stack every fragment of
 a request column-wise into ONE (k, sum L) GF(256) matmul instead of one
@@ -26,6 +28,9 @@ from typing import Dict, List, Sequence, Tuple
 import numpy as np
 
 from repro.core.payload import as_u8, payload_nbytes
+from repro.kernels.platform import on_tpu, require_tpu
+from repro.kernels.rs_gf256.kernel import (gf256_matmul_bitsliced,
+                                           warmup_bitsliced)
 from repro.kernels.rs_gf256.ref import (cauchy_parity_matrix,
                                         gf_inv_matrix_np, gf_matmul_table)
 
@@ -43,9 +48,15 @@ class ECConfig:
 
 
 class RSCodec:
-    def __init__(self, cfg: ECConfig = ECConfig(), *, backend: str = "numpy",
+    def __init__(self, cfg: ECConfig = ECConfig(), *, backend: str = "auto",
                  inv_cache_size: int = 64):
         self.cfg = cfg
+        if backend == "auto":
+            backend = "pallas" if on_tpu() else "numpy"
+        if backend == "pallas":
+            require_tpu("RSCodec")
+        elif backend not in ("numpy", "interpret"):
+            raise ValueError(f"unknown RSCodec backend {backend!r}")
         self.backend = backend
         self._parity = cauchy_parity_matrix(cfg.k, cfg.p)
         self._gen = np.concatenate(
@@ -60,11 +71,20 @@ class RSCodec:
         self._inversions = 0
 
     def _matmul(self, G: np.ndarray, X: np.ndarray) -> np.ndarray:
-        if self.backend == "pallas":
-            from repro.kernels.rs_gf256.ops import gf256_matmul
-            # compiled on TPU, interpret elsewhere (ops.py dispatch)
-            return np.asarray(gf256_matmul(G, X, backend="pallas"))
-        return gf_matmul_table(G, X)
+        if self.backend == "numpy":
+            return gf_matmul_table(G, X)
+        return gf256_matmul_bitsliced(
+            G, X, interpret=self.backend == "interpret")
+
+    def warmup(self) -> None:
+        """Compile the kernel for every tile bucket of both geometries
+        this codec uses — encode (p, k) and decode (k, k) — so serving
+        afterwards compiles nothing. A no-op for the numpy backend."""
+        if self.backend == "numpy":
+            return
+        for m in (self.cfg.p, self.cfg.k):
+            warmup_bitsliced(m, self.cfg.k,
+                             interpret=self.backend == "interpret")
 
     # ---- encode -------------------------------------------------------------
 

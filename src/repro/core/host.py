@@ -54,7 +54,8 @@ parallel under one shared deadline, then joins each process and
 escalates join -> terminate -> kill; a `weakref.finalize` + module
 `atexit` hook reaps abandoned stores so no worker process or /dev/shm
 segment outlives the parent.  Workers are daemonic besides — the
-interpreter will not exit leaving them behind.
+interpreter will not exit leaving them behind.  The same hook then
+stops the forkserver and waits for it (`stop_forkserver`).
 """
 from __future__ import annotations
 
@@ -88,7 +89,7 @@ from .transport import (HeartbeatConfig, LocalTransport, ShardTransport,
 from .writeback import StoreFuture
 
 __all__ = ["ProcessShardedStore", "ShardWorkerDied",
-           "DEFAULT_ARENA_BYTES"]
+           "DEFAULT_ARENA_BYTES", "stop_forkserver"]
 
 _LOG = logging.getLogger("repro.host")
 
@@ -117,8 +118,20 @@ def _portable_exc(e: BaseException) -> BaseException:
         return RuntimeError(f"{type(e).__name__}: {e}")
 
 
+def _pin_off_chip() -> None:
+    """Keep a shard worker off the accelerator. The chip belongs to the
+    process that holds it (the frontend); a worker that let JAX pick its
+    platform would try to open the same TPU. Pinning JAX to the CPU
+    before any backend initialises also makes the worker's `RSCodec`
+    choose the host table. Runs first in every worker entry point."""
+    import jax                       # already loaded by the store stack
+    os.environ["JAX_PLATFORMS"] = "cpu"          # and any child it starts
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _worker_main(spec: dict) -> None:
     """Entry point of one shard worker process."""
+    _pin_off_chip()
     # the parent handles ^C; an interactive SIGINT must not tear the
     # worker down mid-journal-write before the parent's close sequence
     try:
@@ -894,6 +907,7 @@ def _reap_orphans() -> None:         # pragma: no cover - exit path
         resources = list(_LIVE_RESOURCES)
     for r in resources:
         r.reap_all()
+    stop_forkserver()
 
 
 # ---------------------------------------------------------------------------
@@ -922,6 +936,19 @@ def _host_context(method: Optional[str] = None):
                 ctx = mp.get_context("spawn")
             _CTX = ctx
         return _CTX
+
+
+def stop_forkserver() -> None:
+    """Stop the forkserver that workers fork from and wait until it has
+    exited. Left alone, it notices its parent's exit only afterwards and
+    outlives the parent by its own interpreter teardown. Call it once
+    every `ProcessShardedStore` of the process is closed (the next store
+    starts a new forkserver); the module's atexit hook calls it after
+    reaping abandoned stores."""
+    with _CTX_LOCK:
+        if _CTX is not None and _CTX.get_start_method() == "forkserver":
+            from multiprocessing import forkserver
+            forkserver._forkserver._stop()
 
 
 # ---------------------------------------------------------------------------

@@ -50,7 +50,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from .clock import Clock
-from .host import _WorkerLoop, _portable_exc, _swallow
+from .host import _WorkerLoop, _pin_off_chip, _portable_exc, _swallow
 from .locks import make_lock
 from .payload import as_u8
 from .store import InfiniStore
@@ -63,6 +63,7 @@ _LOG = logging.getLogger("repro.netshard")
 
 def _net_worker_main(spec: dict) -> None:
     """Entry point of one networked shard worker process."""
+    _pin_off_chip()
     try:
         signal.signal(signal.SIGINT, signal.SIG_IGN)
     except (ValueError, OSError):                     # pragma: no cover

@@ -411,6 +411,8 @@ class InfiniStore:
             backoff_cap_s=max(cfg.cos_visibility_lag, 0.05),
             seed=seed)
         self.window = SlidingWindow(cfg.gc, self.clock)
+        # the compiled kernel when this process owns a TPU, the numpy
+        # table otherwise (shard workers are pinned to the CPU)
         self.codec = RSCodec(cfg.ec)
         self.mt = MetadataTable()
         self.pb = PersistentBuffer()
@@ -2470,6 +2472,8 @@ class InfiniStore:
         stats = self.stats.as_dict()
         return {"mt": self.mt.snapshot(),
                 "health": self.health(),
+                "codec": {"backend": self.codec.backend,
+                          **self.codec.cache_info()},
                 "chunk_map": dict(self.chunk_map),
                 "stats": stats,
                 "derived": StoreStats.derived(stats),

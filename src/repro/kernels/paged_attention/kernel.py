@@ -63,7 +63,7 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, lens, *,
-                                  interpret: bool = True):
+                                  interpret: bool):
     """q: (B, H, hd); pools: (B, P, ps, K, hd); block_table: (B, P) int32;
     lens: (B,) int32. Returns (B, H, hd) in q.dtype."""
     B, H, hd = q.shape

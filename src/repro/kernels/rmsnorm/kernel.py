@@ -24,7 +24,7 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def rms_norm_pallas(x: jax.Array, scale: jax.Array, eps: float = 1e-6, *,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     orig_shape = x.shape
     d = orig_shape[-1]
     rows = 1

@@ -1,32 +1,29 @@
 """Public op: gf256_matmul with backend dispatch.
 
-On TPU the bit-sliced Pallas kernel runs compiled; everywhere else it
-runs in interpret mode (exercised by tests) or falls back to the jnp
-oracle. The legacy xtime-ladder kernel stays reachable as
-backend="ladder" for A/B benchmarking.
+"pallas" is the bit-sliced kernel compiled for the TPU and raises on any
+other platform; interpret mode runs only when asked for by name.
 """
 from __future__ import annotations
 
-import jax
-
-from repro.kernels.rs_gf256.kernel import (gf256_matmul_bitsliced,
-                                           gf256_matmul_pallas_ladder)
+from repro.kernels.platform import on_tpu, require_tpu
+from repro.kernels.rs_gf256.kernel import gf256_matmul_bitsliced
 from repro.kernels.rs_gf256.ref import gf256_matmul_ref
 
 
 def gf256_matmul(G, X, *, backend: str = "auto"):
     """OUT = G @ X over GF(256). G: (m,k) uint8, X: (k,L) uint8.
 
-    backend: "pallas" (bit-sliced; compiled on TPU, interpret elsewhere),
-             "interpret" (bit-sliced, forced interpret mode),
-             "ladder" (legacy xtime-ladder kernel, interpret off-TPU),
+    backend: "pallas" (bit-sliced kernel, compiled; TPU only),
+             "interpret" (bit-sliced kernel in the Pallas interpreter),
              "ref" (jnp oracle), "auto" (pallas on TPU else ref).
     """
-    on_tpu = jax.default_backend() == "tpu"
-    if backend == "pallas" or (backend == "auto" and on_tpu):
-        return gf256_matmul_bitsliced(G, X, interpret=not on_tpu)
+    if backend == "auto":
+        backend = "pallas" if on_tpu() else "ref"
+    if backend == "pallas":
+        require_tpu("gf256_matmul")
+        return gf256_matmul_bitsliced(G, X, interpret=False)
     if backend == "interpret":
         return gf256_matmul_bitsliced(G, X, interpret=True)
-    if backend == "ladder":
-        return gf256_matmul_pallas_ladder(G, X, interpret=not on_tpu)
-    return gf256_matmul_ref(G, X)
+    if backend == "ref":
+        return gf256_matmul_ref(G, X)
+    raise ValueError(f"unknown gf256_matmul backend {backend!r}")

@@ -87,20 +87,6 @@ def gf_matmul_table(A: np.ndarray, X: np.ndarray) -> np.ndarray:
     return out
 
 
-def gf_coeff_planes(A: np.ndarray) -> np.ndarray:
-    """(m,k) uint8 -> (m,k,8) uint8 companion-matrix bit-planes.
-
-    plane[..., b] = A * 2^b over GF(256) — the image of input bit b under
-    multiplication by each coefficient (column b of the coefficient's 8x8
-    GF(2) companion matrix, packed as a byte). With these, a GF(256)
-    constant multiply is 8 mask-and-XOR steps with no per-bit selects:
-    out = XOR_b spread(bit_b(x)) & plane[b]."""
-    planes = [np.asarray(A, np.uint8)]
-    for _ in range(7):
-        planes.append(gf_mul_np(planes[-1], np.uint8(2)))
-    return np.stack(planes, axis=-1)
-
-
 def gf_inv_matrix_np(M: np.ndarray) -> np.ndarray:
     """Gauss-Jordan inversion over GF(256)."""
     M = np.asarray(M, np.uint8)

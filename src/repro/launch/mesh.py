@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Production mesh construction and per-chip peak figures.
 
 `make_production_mesh` is a FUNCTION (not a module-level constant) so that
 importing this module never touches jax device state. The dry-run sets
@@ -7,56 +7,60 @@ import (see launch/dryrun.py); tests and benchmarks see 1 device.
 """
 from __future__ import annotations
 
+from typing import Dict
+
 import jax
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: pass axis_types=Auto where the
-    API exists (jax >= 0.5); older jax has no AxisType and treats every
-    axis as Auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis Auto (sharding propagated by the
+    compiler, constrained by the logical-axis rules)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, axis_names):
-    """shard_map across jax versions: `jax.shard_map(..., axis_names=...,
-    check_vma=False)` on new jax; on old jax, the experimental shard_map
-    with `auto=` carrying the non-manual axes so only `axis_names` go
-    manual (same partial-manual semantics as the new API). axis_names is
-    required — a default would mean opposite things in the two branches
-    (new jax: all axes manual; old jax: none)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=axis_names, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_old
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False, auto=auto)
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names):
+    """`jax.shard_map` manual over `axis_names` only (the other mesh axes
+    stay Auto), without the varying-manual-axes check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=axis_names,
+                         check_vma=False)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 1, model: int = 1, *, pod: int = 0):
     """Small mesh for CPU tests (fits in however many devices exist)."""
     if pod:
-        return compat_make_mesh((pod, data, model),
-                                ("pod", "data", "model"))
-    return compat_make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
-# TPU v5e hardware model used by the roofline analysis (per chip).
-HW = {
-    "peak_flops_bf16": 197e12,     # FLOP/s
-    "hbm_bw": 819e9,               # B/s
-    "ici_bw": 50e9,                # B/s per link
-    "dcn_bw": 6.25e9,              # B/s per chip across pods (assumption)
-    "hbm_bytes": 16e9,
+# Per-chip peaks keyed by `jax.Device.device_kind`.
+# "TPU v5 lite" is TPU v5e. Source: Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect (4 links of 50 GB/s). The cross-pod DCN share per chip is
+# an assumption of the roofline model, not a published figure.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,     # FLOP/s
+        "hbm_bw": 819e9,               # B/s
+        "ici_bw": 50e9,                # B/s per link
+        "dcn_bw": 6.25e9,              # B/s per chip across pods (assumed)
+        "hbm_bytes": 16e9,
+    },
 }
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peak figures of one chip; an unknown device is an error, never a
+    default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak figures for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None

@@ -12,6 +12,7 @@ import argparse
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import ServeConfig, ServeEngine
 
 
@@ -26,6 +27,7 @@ def main() -> None:
                     help="evict seq0's pages to COS and resume it "
                          "(device-payload on-demand migration)")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = reduced(get_config(args.arch))
     eng = ServeEngine(cfg, ServeConfig(batch_slots=args.batch,
                                        max_len=args.prompt_len

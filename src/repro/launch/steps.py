@@ -120,8 +120,8 @@ def make_train_step_compressed(model: Model, opt_cfg: adamw.AdamWConfig,
             inner["batch"] = "data"
             set_global_rules(inner)
         try:
-            from repro.launch.mesh import compat_shard_map
-            grads, new_err, loss = compat_shard_map(
+            from repro.launch.mesh import shard_map
+            grads, new_err, loss = shard_map(
                 per_pod, mesh=mesh, axis_names={"pod"},
                 in_specs=(g_spec, b_spec, g_spec),
                 out_specs=(g_spec, g_spec, P()),

@@ -27,6 +27,7 @@ from repro.core.ec import ECConfig
 from repro.core.gc_window import GCConfig
 from repro.data.pipeline import TokenPipeline
 from repro.distributed.sharding import make_rules, set_global_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.launch.steps import make_train_step
 from repro.models import build_model
@@ -105,6 +106,7 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--checkpoint-every", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -32,17 +32,17 @@ def test_error_feedback_accumulates_to_truth():
 
 def test_psum_compressed_single_pod_identity():
     """With one pod the compressed exchange must return ~the input."""
-    from repro.launch.mesh import compat_make_mesh, compat_shard_map
-    mesh = compat_make_mesh((1,), ("pod",))
+    from repro.launch.mesh import make_mesh, shard_map
+    mesh = make_mesh((1,), ("pod",))
     g = {"w": jax.random.normal(jax.random.PRNGKey(2), (64,))}
     e = {"w": jnp.zeros((64,))}
 
     def f(g, e):
         return C.psum_compressed(g, "pod", e)
 
-    out, new_e = compat_shard_map(f, mesh=mesh, axis_names={"pod"},
-                                  in_specs=(P(), P()),
-                                  out_specs=(P(), P()))(g, e)
+    out, new_e = shard_map(f, mesh=mesh, axis_names={"pod"},
+                           in_specs=(P(), P()),
+                           out_specs=(P(), P()))(g, e)
     np.testing.assert_allclose(np.asarray(out["w"] + new_e["w"]),
                                np.asarray(g["w"]), atol=1e-5)
 

@@ -7,8 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core.ec import ECConfig, RSCodec
-from repro.kernels.rs_gf256.kernel import (gf256_matmul_bitsliced,
-                                           gf256_matmul_pallas_ladder)
+from repro.kernels.rs_gf256.kernel import gf256_matmul_bitsliced
 from repro.kernels.rs_gf256.ref import (gf256_matmul_ref, gf_matmul_np,
                                         gf_matmul_table)
 
@@ -115,15 +114,6 @@ def test_bitsliced_bit_identical_randomized(seed):
     assert np.array_equal(gf_matmul_table(G, X), want)
     got = np.asarray(gf256_matmul_bitsliced(G, X, interpret=True))
     assert np.array_equal(got, want)
-
-
-def test_bitsliced_matches_ladder():
-    rng = np.random.default_rng(42)
-    G = rng.integers(0, 256, (4, 6)).astype(np.uint8)
-    X = rng.integers(0, 256, (6, 2048 + 77)).astype(np.uint8)
-    a = np.asarray(gf256_matmul_bitsliced(G, X, interpret=True))
-    b = np.asarray(gf256_matmul_pallas_ladder(G, X, interpret=True))
-    assert np.array_equal(a, b)
 
 
 # ---------------------------------------------------------------------------

@@ -191,6 +191,43 @@ def test_abandoned_store_reaped_by_finalizer(tmp_path):
     assert _shm_segments() - before == set()
 
 
+_EXIT_CHILD = """
+import json, sys
+from multiprocessing import forkserver
+from repro.core import Clock, ProcessShardedStore, StoreConfig
+from repro.core.ec import ECConfig
+cfg = StoreConfig(ec=ECConfig(k=4, p=2), function_capacity=8 << 20,
+                  fragment_bytes=1 << 20, spill_dir=None)
+st = ProcessShardedStore(cfg, num_shards=2, clock=Clock(),
+                         cos_root=sys.argv[1])
+st.put("k", b"k" * 9_000)
+pids = list(st.worker_pids()) + [forkserver._forkserver._forkserver_pid]
+if sys.argv[2] == "close":
+    assert st.close()
+print(json.dumps(pids), flush=True)
+"""
+
+
+@pytest.mark.parametrize("how", ["close", "abandon"])
+def test_interpreter_exit_leaves_no_process(tmp_path, how):
+    """Once an interpreter that used the process host has exited, none
+    of its workers and not its forkserver is still running: the exit
+    hook reaps what was left open, then stops the forkserver and waits
+    for it rather than leaving it to notice the exit afterwards."""
+    import json
+    import subprocess
+    import sys
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    out = subprocess.run(
+        [sys.executable, "-c", _EXIT_CHILD, str(tmp_path / "cos"), how],
+        env=env, capture_output=True, text=True, check=True, timeout=120)
+    pids = json.loads(out.stdout.strip().splitlines()[-1])
+    assert len(pids) == 3 and all(isinstance(p, int) for p in pids)
+    for pid in pids:                 # gone now, not after a grace period
+        with pytest.raises(ProcessLookupError):
+            os.kill(pid, 0)
+
+
 def test_close_escalates_past_stuck_worker(tmp_path):
     """A worker that cannot answer its close RPC (SIGSTOPped here) must
     not hold the host hostage: the shared deadline expires and reaping
@@ -377,3 +414,26 @@ def test_worker_fault_plan_fires_in_worker(tmp_path):
         assert state in ("DEGRADED_WRITEBACK", "OK")
     finally:
         st.close(flush=False)
+
+
+@pytest.mark.parametrize("transport", ["shm", "tcp"])
+def test_workers_pinned_off_chip(tmp_path, monkeypatch, transport):
+    """A shard worker never opens the accelerator its parent holds: even
+    when the environment it inherits asks JAX for a TPU, the worker pins
+    JAX to the CPU before its store picks a codec, so it boots (here,
+    with no TPU, an unpinned worker would fail to initialise JAX) and
+    its codec is the host table."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    cfg = StoreConfig(ec=ECConfig(k=4, p=2), function_capacity=8 * MB,
+                      fragment_bytes=1 * MB, spill_dir=None)
+    st = ProcessShardedStore(cfg, num_shards=2, clock=Clock(),
+                             cos_root=str(tmp_path / "cos"),
+                             start_method="spawn", transport=transport)
+    try:
+        vals = {f"k{i}": os.urandom(20_000 + i) for i in range(6)}
+        st.put_many(list(vals.items()))
+        assert st.get_many(list(vals)) == vals
+        shards = st.snapshot_metadata()["shards"]
+        assert [s["codec"]["backend"] for s in shards] == ["numpy"] * 2
+    finally:
+        assert st.close()

@@ -58,3 +58,11 @@ def test_permutation_invariance():
                                          interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                atol=1e-5, rtol=1e-5)
+
+
+def test_pallas_backend_requires_tpu():
+    """backend="pallas" means compiled: off a TPU it raises."""
+    from repro.kernels.paged_attention.ops import paged_decode_attention
+    args = _case(3, 1, 2, 4, 1, 1, 8, jnp.float32)
+    with pytest.raises(RuntimeError, match="TPU"):
+        paged_decode_attention(*args, backend="pallas")

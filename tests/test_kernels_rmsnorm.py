@@ -19,3 +19,11 @@ def test_matches_ref(shape, dtype):
     got = np.asarray(rms_norm_pallas(x, scale, interpret=True), np.float32)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
+def test_pallas_backend_requires_tpu():
+    """backend="pallas" means compiled: off a TPU it raises."""
+    from repro.kernels.rmsnorm.ops import rms_norm_op
+    x = jnp.ones((4, 128), jnp.float32)
+    with pytest.raises(RuntimeError, match="TPU"):
+        rms_norm_op(x, jnp.ones((128,), jnp.float32), backend="pallas")

@@ -73,3 +73,13 @@ def test_vocab_padding_is_lane_aligned():
     assert padded_vocab(151655) % 128 == 0
     assert padded_vocab(151936) == 151936        # already aligned
     assert padded_vocab(49155) % 16 == 0
+
+
+def test_peaks_keyed_by_device_kind():
+    """Chip peaks come from one table keyed by device_kind; an unknown
+    device is an error, not a default."""
+    from repro.launch.mesh import peaks
+    v5e = peaks("TPU v5 lite")
+    assert v5e["peak_flops_bf16"] == 197e12 and v5e["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="cpu"):
+        peaks("cpu")
