@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.core.payload import as_u8
 from repro.core.store import StoreFrontend
+from repro.obs import NOOP_CM
 
 PyTree = Any
 
@@ -81,7 +82,20 @@ class Checkpointer:
         return f"{self.cfg.prefix}/manifest/{step:08d}"
 
     def save(self, step: int, state: PyTree) -> None:
+        # the store's ObsPlane, if it has one: a "ckpt.save" span with
+        # the stages "ckpt.d2h" (each leaf's host copy) and "ckpt.wait"
+        # (waits on the PUT batches)
+        obs = getattr(self.store, "obs", None)
+        with (obs.span("ckpt.save", step=step)
+              if obs is not None else NOOP_CM) as span:
+            nbytes = self._save(step, state, obs)
+            if span is not None:
+                span.tags["bytes"] = nbytes
+
+    def _save(self, step: int, state: PyTree, obs) -> int:
+        """`save`'s body; returns the bytes of the leaves saved."""
         leaves = _leaf_paths(state)
+        total = 0
         manifest = {"step": step, "leaves": []}
         # shards ride pipelined async batched PUTs, flushed in bounded
         # sub-batches so peak host memory stays O(limit) (encode_many
@@ -94,7 +108,12 @@ class Checkpointer:
         for name, leaf in leaves:
             # ONE device-to-host transfer per leaf; everything downstream
             # operates on this flat uint8 view (no bytes serialization)
-            u8 = as_u8(leaf)
+            with (obs.span("ckpt.d2h") if obs is not None
+                  else NOOP_CM) as span:
+                u8 = as_u8(leaf)
+                if span is not None:
+                    span.tags["bytes"] = int(u8.size)
+            total += int(u8.size)
             nshards = max(1, -(-u8.size // self.cfg.leaf_shard_bytes))
             for si in range(nshards):
                 lo = si * self.cfg.leaf_shard_bytes
@@ -105,7 +124,9 @@ class Checkpointer:
                     inflight.append(self.store.put_many_async(sub))
                     sub, sub_bytes = [], 0
                     while len(inflight) >= self.cfg.max_inflight_batches:
-                        inflight.pop(0).result()
+                        with (obs.span("ckpt.wait") if obs is not None
+                              else NOOP_CM):
+                            inflight.pop(0).result()
             # dtype/shape come from the handle — no second host transfer
             dtype = getattr(leaf, "dtype", None)
             shape = getattr(leaf, "shape", None)
@@ -118,13 +139,15 @@ class Checkpointer:
                  "nbytes": int(u8.size)})
         if sub:
             inflight.append(self.store.put_many_async(sub))
-        for fut in inflight:
-            fut.result()                         # SMS-accept barrier
+        with (obs.span("ckpt.wait") if obs is not None else NOOP_CM):
+            for fut in inflight:
+                fut.result()                     # SMS-accept barrier
         self.store.put(self._manifest_key(step),
                        json.dumps(manifest).encode())
         with self._lock:
             self._saved_steps.append(step)
             self._gc_old()
+        return total
 
     def _leaf_key(self, step: int, name: str, shard: int) -> str:
         return f"{self.cfg.prefix}/{step:08d}/{name}/s{shard}"

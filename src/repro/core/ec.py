@@ -33,6 +33,7 @@ from repro.kernels.rs_gf256.kernel import (gf256_matmul_bitsliced,
                                            warmup_bitsliced)
 from repro.kernels.rs_gf256.ref import (cauchy_parity_matrix,
                                         gf_inv_matrix_np, gf_matmul_table)
+from repro.obs import NOOP_CM
 
 _HEADER = struct.Struct("<I")    # original length prefix
 
@@ -49,8 +50,11 @@ class ECConfig:
 
 class RSCodec:
     def __init__(self, cfg: ECConfig = ECConfig(), *, backend: str = "auto",
-                 inv_cache_size: int = 64):
+                 inv_cache_size: int = 64, obs=None):
         self.cfg = cfg
+        # optional ObsPlane (repro.obs), the owning store's: "ec.frame"
+        # spans here, "ec.dispatch" / "ec.collect" in the kernel wrapper
+        self.obs = obs
         if backend == "auto":
             backend = "pallas" if on_tpu() else "numpy"
         if backend == "pallas":
@@ -74,7 +78,7 @@ class RSCodec:
         if self.backend == "numpy":
             return gf_matmul_table(G, X)
         return gf256_matmul_bitsliced(
-            G, X, interpret=self.backend == "interpret")
+            G, X, interpret=self.backend == "interpret", obs=self.obs)
 
     def warmup(self) -> None:
         """Compile the kernel for every tile bucket of both geometries
@@ -107,11 +111,14 @@ class RSCodec:
             return []
         k, p = self.cfg.k, self.cfg.p
         clens = [self.chunk_len(payload_nbytes(pl)) for pl in payloads]
-        data = np.zeros((k, int(sum(clens))), np.uint8)
-        off = 0
-        for pl, clen in zip(payloads, clens):
-            self._fill_framed(data[:, off:off + clen], as_u8(pl))
-            off += clen
+        obs = self.obs
+        with (obs.span("ec.frame", bytes=k * int(sum(clens)))
+              if obs is not None else NOOP_CM):
+            data = np.zeros((k, int(sum(clens))), np.uint8)
+            off = 0
+            for pl, clen in zip(payloads, clens):
+                self._fill_framed(data[:, off:off + clen], as_u8(pl))
+                off += clen
         parity = self._matmul(self._parity, data)
         out: List[List[bytes]] = []
         off = 0

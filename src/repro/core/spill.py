@@ -66,6 +66,7 @@ from __future__ import annotations
 import os
 import struct
 import threading
+import time
 import zlib
 
 try:
@@ -182,7 +183,9 @@ class SpillJournal:
         self.faults = faults
         # optional ObsPlane (repro.obs), attached by the owning store
         # after construction: "journal.append" / "journal.sync" spans
-        # around the ack-path journal work
+        # around the ack-path journal work, and inside them the stages
+        # "journal.lock_wait", "journal.digest", "journal.write",
+        # "journal.compact" and "journal.segment"
         self.obs = None
         self.dir = Path(path)
         self.dir.mkdir(parents=True, exist_ok=True)
@@ -229,6 +232,9 @@ class SpillJournal:
         # executor-side counters for the ACTIVE file: bytes written vs
         # bytes known flushed (hard close truncates to the latter)
         self._written = self._synced = 0
+        # with a plane: bytes of payload-less frames written since the
+        # last flush, counted on that flush's "journal.write" span
+        self._small_bytes = 0
         self.stats.segments_created += 1
         self._seg_live.setdefault(self._active_id, 0)
         self._seg_live_bytes.setdefault(self._active_id, 0)
@@ -356,7 +362,10 @@ class SpillJournal:
         obs = self.obs
         with (obs.span("journal.append")
               if obs is not None else NOOP_CM):
+            t_lock = time.perf_counter() if obs is not None else 0.0
             with self._lock:
+                if obs is not None:
+                    obs.span_since("journal.lock_wait", t_lock)
                 return self._append_locked(key, data)
 
     def append_many(self, items) -> List[int]:
@@ -367,7 +376,10 @@ class SpillJournal:
         obs = self.obs
         with (obs.span("journal.append", n=len(items))
               if obs is not None else NOOP_CM):
+            t_lock = time.perf_counter() if obs is not None else 0.0
             with self._lock:
+                if obs is not None:
+                    obs.span_since("journal.lock_wait", t_lock)
                 return [self._append_locked(k, d) for k, d in items]
 
     def _append_locked(self, key: str, data) -> int:
@@ -427,7 +439,10 @@ class SpillJournal:
         obs = self.obs
         with (obs.span("journal.sync")
               if obs is not None else NOOP_CM):
+            t_lock = time.perf_counter() if obs is not None else 0.0
             with self._lock:
+                if obs is not None:
+                    obs.span_since("journal.lock_wait", t_lock)
                 if self._closed:
                     return
                 self._submit(("flush",))
@@ -487,24 +502,58 @@ class SpillJournal:
 
     def _exec_op(self, op: tuple) -> None:
         kind = op[0]
+        obs = self.obs
         if kind == "frame":
             if self.faults is not None:
                 self.faults.fire("spill.io")     # writer-side I/O error
             _, rtype, seq, kb, body = op
             nbytes = payload_nbytes(body)
             meta = _META_S.pack(rtype, seq, len(kb), nbytes)
-            # one small coalesced write (header + key), then the payload
-            # as its own write so large bodies bypass the buffer copy
-            self._f.write(_MAGIC_S.pack(_MAGIC) + meta
-                          + _CRC_S.pack(_frame_crc(meta, kb, body)) + kb)
-            if nbytes:
-                self._f.write(body)
-            self._written += _HDR_LEN + len(kb) + nbytes
-            if self.sync_each:
-                self._do_flush()             # survives a process crash
+            if obs is not None and nbytes:
+                with obs.span("journal.digest", bytes=nbytes):
+                    crc = _frame_crc(meta, kb, body)
+            else:        # no plane, or no payload to digest: no span
+                crc = _frame_crc(meta, kb, body)
+            frame_len = _HDR_LEN + len(kb) + nbytes
+            # a frame with no payload (a PERSIST record, a chunk stub) is
+            # a few dozen bytes the writer buffers: no span of its own,
+            # its bytes count on the span of the flush that writes it
+            if obs is not None and obs.enabled and not nbytes \
+                    and not self.sync_each:
+                self._small_bytes += frame_len
+                cm = NOOP_CM
+            else:
+                cm = (obs.span("journal.write", bytes=frame_len)
+                      if obs is not None else NOOP_CM)
+            with cm:
+                # one small coalesced write (header + key), then the
+                # payload as its own write so large bodies bypass the
+                # buffer copy
+                self._f.write(_MAGIC_S.pack(_MAGIC) + meta
+                              + _CRC_S.pack(crc) + kb)
+                if nbytes:
+                    self._f.write(body)
+                self._written += frame_len
+                if self.sync_each:
+                    self._do_flush()         # survives a process crash
         elif kind == "flush":
-            self._do_flush()
-        elif kind == "rotate":
+            small, self._small_bytes = self._small_bytes, 0
+            with (obs.span("journal.write", bytes=small)
+                  if obs is not None else NOOP_CM):
+                self._do_flush()
+        elif kind == "compact":
+            with (obs.span("journal.compact",
+                           bytes=sum(ln for _, ln in op[2]))
+                  if obs is not None else NOOP_CM):
+                self._compact_copy(op[1], op[2])
+        else:
+            with (obs.span("journal.segment", op=kind)
+                  if obs is not None else NOOP_CM):
+                self._segment_op(op)
+
+    def _segment_op(self, op: tuple) -> None:
+        kind = op[0]
+        if kind == "rotate":
             _, old_id, delete_old, new_id = op
             self._do_flush()                 # seal durably: fsync=True
             self._f.close()                  # must cover sealed frames
@@ -519,22 +568,22 @@ class SpillJournal:
             self._written = self._synced = 0
         elif kind == "unlink":
             self._seg_path(op[1]).unlink(missing_ok=True)
-        elif kind == "compact":
-            _, src, entries = op
-            try:
-                data = src.read_bytes()
-            except FileNotFoundError:
-                return
-            for off, ln in entries:
-                self._f.write(data[off:off + ln])
-                self._written += ln
-            # The copies are about to become the ONLY durable frames for
-            # these records: flush them (honoring fsync) before the
-            # sealed source is destroyed, else a crash in between loses
-            # acked data. _do_flush also advances _synced so a hard
-            # close cannot truncate the compacted frames away.
-            self._do_flush()
-            src.unlink(missing_ok=True)
+
+    def _compact_copy(self, src: Path, entries) -> None:
+        try:
+            data = src.read_bytes()
+        except FileNotFoundError:
+            return
+        for off, ln in entries:
+            self._f.write(data[off:off + ln])
+            self._written += ln
+        # The copies are about to become the ONLY durable frames for
+        # these records: flush them (honoring fsync) before the sealed
+        # source is destroyed, else a crash in between loses acked data.
+        # _do_flush also advances _synced so a hard close cannot
+        # truncate the compacted frames away.
+        self._do_flush()
+        src.unlink(missing_ok=True)
 
     def _do_flush(self) -> None:
         self._f.flush()

@@ -57,6 +57,7 @@ accounting.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import logging
@@ -411,9 +412,6 @@ class InfiniStore:
             backoff_cap_s=max(cfg.cos_visibility_lag, 0.05),
             seed=seed)
         self.window = SlidingWindow(cfg.gc, self.clock)
-        # the compiled kernel when this process owns a TPU, the numpy
-        # table otherwise (shard workers are pinned to the CPU)
-        self.codec = RSCodec(cfg.ec)
         self.mt = MetadataTable()
         self.pb = PersistentBuffer()
         self.logs: Dict[int, InsertionLog] = {}
@@ -431,6 +429,9 @@ class InfiniStore:
         if cfg.faults is not None and self._obs is not None:
             # mirror fault-plane fires into the flight recorder
             cfg.faults.obs = self._obs
+        # the compiled kernel when this process owns a TPU, the numpy
+        # table otherwise (shard workers are pinned to the CPU)
+        self.codec = RSCodec(cfg.ec, obs=self._obs)
         # crash-consistent spill journal (§5.3.2): the writeback queue
         # appends every enqueue here before the PUT acks; metadata
         # records ("meta/<key>|<ver>") journal the table entry so a
@@ -554,9 +555,14 @@ class InfiniStore:
     def _register_daemon(self) -> None:
         self._daemon_ident = threading.get_ident()
 
-    def _submit(self, fn) -> StoreFuture:
+    def _submit(self, fn, *, op: str = "call", n: int = 1,
+                t_submit: Optional[float] = None) -> StoreFuture:
         obs = self._obs
         if obs is not None:
+            # the task's wait in the daemon's queue, from `t_submit`
+            # (now, unless the caller took it) to its start on the daemon
+            t_sub = time.perf_counter() if t_submit is None else t_submit
+            fn = functools.partial(self._dequeued, obs, t_sub, op, n, fn)
             # executor hop: the daemon runs `fn` on its own thread —
             # close it over the submitter's ambient trace context so
             # daemon-side spans stitch under the caller's span
@@ -587,6 +593,13 @@ class InfiniStore:
                 f"store {self.name!r} daemon is shut down",
                 op="submit") from e
         return fut
+
+    @staticmethod
+    def _dequeued(obs: ObsPlane, t_submit: float, op: str, n: int, fn):
+        """Run a task the daemon took from its queue, first recording
+        its wait there as a `daemon.queue` span."""
+        obs.span_since("daemon.queue", t_submit, op=op, n=n)
+        return fn()
 
     def flush_writeback(self, timeout: Optional[float] = None) -> bool:
         """Barrier: block until every acked PUT is persisted in COS.
@@ -1164,9 +1177,12 @@ class InfiniStore:
         The payload is captured at submission — the caller may mutate or
         reuse its buffer immediately."""
         value = self._snapshot_value(value)
+        t_sub = time.perf_counter() if self._obs is not None else 0.0
         return self._submit(
             lambda: self._put_many_impl([(key, value)],
-                                        raise_on_conflict=True)[key])
+                                        raise_on_conflict=True,
+                                        t_submit=t_sub)[key],
+            op="put", t_submit=t_sub)
 
     def put_many(self, items, *, raise_on_conflict: bool = False
                  ) -> Dict[str, int]:
@@ -1191,16 +1207,20 @@ class InfiniStore:
         obs = self._obs
         with (obs.span("client.put_many", n=len(items))
               if obs is not None else NOOP_CM):
+            t_sub = time.perf_counter() if obs is not None else 0.0
             return self._submit(
                 lambda: self._put_many_impl(
-                    items, raise_on_conflict=raise_on_conflict))
+                    items, raise_on_conflict=raise_on_conflict,
+                    t_submit=t_sub),
+                op="put", n=len(items), t_submit=t_sub)
 
-    def _put_many_impl(self, items, *, raise_on_conflict: bool = False
-                       ) -> Dict[str, int]:
+    def _put_many_impl(self, items, *, raise_on_conflict: bool = False,
+                       t_submit: float = 0.0) -> Dict[str, int]:
         """Single-store PUT batch: prepare + immediate self-commit (the
-        degenerate one-shard case of the cross-shard protocol)."""
+        degenerate one-shard case of the cross-shard protocol).
+        `t_submit` is the perf_counter reading taken at submission, the
+        start of the `put.ack_us` sample."""
         obs = self._obs
-        t0 = time.perf_counter() if obs is not None else 0.0
         with (obs.span("daemon.put_many", n=len(items))
               if obs is not None else NOOP_CM):
             prep = self._put_many_prepare(
@@ -1214,7 +1234,8 @@ class InfiniStore:
                 self._put_many_abort(prep)
                 raise
         if obs is not None:
-            obs.record("put.ack_us", (time.perf_counter() - t0) * 1e6)
+            obs.record("put.ack_us",
+                       (time.perf_counter() - t_submit) * 1e6)
         return out
 
     def prepare_put_many_async(self, items, *,
@@ -1598,13 +1619,17 @@ class InfiniStore:
         # scale-out. Multi-fragment batches still compact each chunk out
         # so one long-lived chunk never pins the whole batch buffer.
         compact = len(frags) > 1
+        nbytes = sum(len(cs[0]) * len(cs) for cs in all_chunks) \
+            if obs is not None else 0
         groups: Dict[int, List[Tuple[str, str, object]]] = {}
-        for (fkey, _), chunks in zip(frags, all_chunks):
-            for idx, chunk in enumerate(chunks):
-                ckey = f"{fkey}#{idx}"
-                fid = self._place_chunk(idx, len(chunk))
-                groups.setdefault(fid, []).append(
-                    (fkey, ckey, chunk.copy() if compact else chunk))
+        with (obs.span("sms.place", bytes=nbytes, copy=compact)
+              if obs is not None else NOOP_CM):
+            for (fkey, _), chunks in zip(frags, all_chunks):
+                for idx, chunk in enumerate(chunks):
+                    ckey = f"{fkey}#{idx}"
+                    fid = self._place_chunk(idx, len(chunk))
+                    groups.setdefault(fid, []).append(
+                        (fkey, ckey, chunk.copy() if compact else chunk))
         if self.spill is not None and self.cfg.async_writeback:
             # journal each fragment's pre-EC payload ONCE (zero-copy u8
             # view — the chunks are deterministically derivable) plus a
@@ -1623,6 +1648,35 @@ class InfiniStore:
                 self._spill_put_frag_seqs[fkey] = seq
             for ckey, seq in zip(ckeys, seqs[len(frags):]):
                 self._spill_put_seqs[ckey] = seq
+        with (obs.span("sms.store", bytes=nbytes)
+              if obs is not None else NOOP_CM):
+            failed = self._store_chunks(groups)
+        # failed fragments' pre-appended journal records die here;
+        # surviving fragments' records commit (dropped when the buffer
+        # entry drains — _on_chunk_persisted / the ack-point release).
+        # Only the leftover CHUNK stubs are killed — _spill_abort_chunks
+        # would also void the surviving fragments' payload records,
+        # losing acked data on a crash (the mixed-failure-batch hole)
+        if self._spill_put_seqs:
+            seqs, self._spill_put_seqs = self._spill_put_seqs, {}
+            for seq in seqs.values():
+                self.spill.mark_persisted(seq)
+        if self._spill_put_frag_seqs:
+            frag_seqs, self._spill_put_frag_seqs = \
+                self._spill_put_frag_seqs, {}
+            for fkey, seq in frag_seqs.items():
+                if fkey in failed:
+                    self.spill.mark_persisted(seq)
+                else:
+                    with self._lock:
+                        self._spill_frag_seqs[fkey] = seq
+        return failed
+
+    def _store_chunks(self, groups: Dict[int, List[Tuple[str, str, object]]]
+                      ) -> Set[str]:
+        """Write placed chunks into their slabs (phase 1), then roll the
+        failed fragments back out and publish the rest (phase 2).
+        Returns the keys of the fragments whose chunks failed."""
         # phase 1: slab writes only, so a fragment can still fail before
         # anything about it becomes durable
         failed: Set[str] = set()
@@ -1678,25 +1732,6 @@ class InfiniStore:
                 slab.log_hash = log.last_hash
                 slab.diff_rank = log.diff_rank
                 self.daemon_view[fid] = log.piggyback()
-        # failed fragments' pre-appended journal records die here;
-        # surviving fragments' records commit (dropped when the buffer
-        # entry drains — _on_chunk_persisted / the ack-point release).
-        # Only the leftover CHUNK stubs are killed — _spill_abort_chunks
-        # would also void the surviving fragments' payload records,
-        # losing acked data on a crash (the mixed-failure-batch hole)
-        if self._spill_put_seqs:
-            seqs, self._spill_put_seqs = self._spill_put_seqs, {}
-            for seq in seqs.values():
-                self.spill.mark_persisted(seq)
-        if self._spill_put_frag_seqs:
-            frag_seqs, self._spill_put_frag_seqs = \
-                self._spill_put_frag_seqs, {}
-            for fkey, seq in frag_seqs.items():
-                if fkey in failed:
-                    self.spill.mark_persisted(seq)
-                else:
-                    with self._lock:
-                        self._spill_frag_seqs[fkey] = seq
         return failed
 
     # ------------------------------------------------------------------
@@ -1708,7 +1743,8 @@ class InfiniStore:
 
     def get_async(self, key: str) -> StoreFuture:
         """Non-blocking GET; the future resolves to bytes or None."""
-        return self._submit(lambda: self._get_many_impl([key])[key])
+        return self._submit(lambda: self._get_many_impl([key])[key],
+                            op="get")
 
     def get_many(self, keys) -> Dict[str, Optional[bytes]]:
         return self.get_many_async(keys).result()
@@ -1722,7 +1758,8 @@ class InfiniStore:
         obs = self._obs
         with (obs.span("client.get_many", n=len(keys))
               if obs is not None else NOOP_CM):
-            return self._submit(lambda: self._get_many_impl(keys))
+            return self._submit(lambda: self._get_many_impl(keys),
+                                op="get", n=len(keys))
 
     def get_array(self, key: str) -> Optional[np.ndarray]:
         """GET returning a flat uint8 array (no bytes materialization) —
@@ -1735,7 +1772,8 @@ class InfiniStore:
     def get_many_arrays_async(self, keys) -> StoreFuture:
         keys = list(keys)
         return self._submit(
-            lambda: self._get_many_impl(keys, as_arrays=True))
+            lambda: self._get_many_impl(keys, as_arrays=True),
+            op="get", n=len(keys))
 
     def _get_many_impl(self, keys, *, as_arrays: bool = False) -> Dict:
         obs = self._obs

@@ -175,7 +175,7 @@ def fault_site(tm: TreeModel) -> List[Finding]:
 # rule: metric-site
 # ---------------------------------------------------------------------------
 
-_OBS_CALLS = ("span", "record", "event")
+_OBS_CALLS = ("span", "span_since", "record", "event")
 
 
 def _is_obs_recv(recv: str) -> bool:
@@ -185,11 +185,12 @@ def _is_obs_recv(recv: str) -> bool:
 
 
 def metric_site(tm: TreeModel) -> List[Finding]:
-    """Every `<obs>.span/record/event(...)` instrumentation site must
-    (a) sit under an `<obs> is not None` guard (the plane is optional
-    and off by default), and (b) pass a literal site name registered in
-    `repro.obs.sites.METRIC_SITES` — a typo'd site would silently
-    record into nothing (span/event) or KeyError at runtime (record)."""
+    """Every `<obs>.span/span_since/record/event(...)` instrumentation
+    site must (a) sit under an `<obs> is not None` guard (the plane is
+    optional and off by default), and (b) pass a literal site name
+    registered in `repro.obs.sites.METRIC_SITES` — a typo'd site would
+    silently record into nothing (span/event) or KeyError at runtime
+    (record)."""
     findings: List[Finding] = []
     manifest = tm.metric_manifest
     for (modname, qual), fm in tm.funcs.items():

@@ -49,6 +49,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs import NOOP_CM
+
 SUBLANES, LANES = 8, 128
 BLOCK_ROWS = 256                 # sublane rows of 128 words per grid step
 _ROW_BYTES = 4 * LANES           # bytes in one (1, 128) uint32 row
@@ -129,7 +131,8 @@ def column_tiles(L: int):
             for off in range(0, L, MAX_TILE)]
 
 
-def gf256_matmul_bitsliced(G, X, *, interpret: bool) -> np.ndarray:
+def gf256_matmul_bitsliced(G, X, *, interpret: bool,
+                           obs=None) -> np.ndarray:
     """Bit-sliced GF(256) matmul. G: (m,k) uint8, X: (k,L) uint8 ->
     (m, L) uint8 numpy, bit-identical to `gf_matmul_table`.
 
@@ -137,21 +140,31 @@ def gf256_matmul_bitsliced(G, X, *, interpret: bool) -> np.ndarray:
     zeroed bucket-wide buffer and reinterprets it as uint32 words (a view,
     not a pass over the data). Every tile is dispatched before the first
     result is read, so transfers and kernels of successive tiles
-    overlap."""
+    overlap. With an ObsPlane `obs`, the dispatch of the tiles is one
+    "ec.dispatch" span (tagged with the padded bytes sent to the device)
+    and reading the results back one "ec.collect" span (the bytes read
+    back)."""
     G8 = np.asarray(G, np.uint8)
     X8 = np.asarray(X, np.uint8)
     m, k = G8.shape
     L = X8.shape[1]
+    tiles = column_tiles(L)
+    padded = sum(bucket for _, _, bucket in tiles)
     pending = []
-    for off, w, bucket in column_tiles(L):
-        tile = np.zeros((k, bucket), np.uint8)
-        tile[:, :w] = X8[:, off:off + w]
-        words = tile.view(np.uint32).reshape(k, -1, LANES)
-        pending.append((off, w, _matmul_tile(G8, words, interpret=interpret)))
+    with (obs.span("ec.dispatch", bytes=k * padded)
+          if obs is not None else NOOP_CM):
+        for off, w, bucket in tiles:
+            tile = np.zeros((k, bucket), np.uint8)
+            tile[:, :w] = X8[:, off:off + w]
+            words = tile.view(np.uint32).reshape(k, -1, LANES)
+            pending.append(
+                (off, w, _matmul_tile(G8, words, interpret=interpret)))
     out = np.empty((m, L), np.uint8)
-    for off, w, res in pending:
-        out[:, off:off + w] = \
-            np.asarray(res).reshape(m, -1).view(np.uint8)[:, :w]
+    with (obs.span("ec.collect", bytes=m * padded)
+          if obs is not None else NOOP_CM):
+        for off, w, res in pending:
+            out[:, off:off + w] = \
+                np.asarray(res).reshape(m, -1).view(np.uint8)[:, :w]
     return out
 
 

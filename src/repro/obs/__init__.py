@@ -25,5 +25,5 @@ from repro.obs.plane import (ObsPlane,  # noqa: F401
                              merge_metric_snapshots)
 from repro.obs.recorder import FlightRecorder  # noqa: F401
 from repro.obs.sites import (EVENT_SITES, HISTOGRAM_SITES,  # noqa: F401
-                             METRIC_SITES, SPAN_SITES)
+                             METRIC_SITES, SPAN_SITES, STAGE_SITES)
 from repro.obs.trace import NOOP_CM, Span, Tracer, current, use  # noqa: F401

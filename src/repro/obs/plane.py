@@ -31,7 +31,8 @@ from repro.obs import trace as _trace
 from repro.obs.metrics import (LatencyHistogram, merge_counts, summarize,
                                to_prometheus)
 from repro.obs.recorder import FlightRecorder
-from repro.obs.sites import HISTOGRAM_SITES, METRIC_SITES
+from repro.obs.sites import (FLIGHT_SPAN_SITES, HISTOGRAM_SITES,
+                             METRIC_SITES, STAGE_SITES)
 from repro.obs.trace import NOOP_CM, Tracer
 
 _PLANES: "weakref.WeakSet[ObsPlane]" = weakref.WeakSet()
@@ -86,10 +87,21 @@ class ObsPlane:
 
     def span(self, site: str, **tags):
         """Context manager opening one span as a child of the ambient
-        context. Disabled plane: a shared no-op CM, no allocation."""
+        context; a stage site (`STAGE_SITES`) also holds a profiler
+        annotation of its name. Disabled plane: a shared no-op CM, no
+        allocation."""
         if not self.enabled:
             return NOOP_CM
-        return self._tracer.start(self, site, tags)
+        return self._tracer.start(self, site, tags, site in STAGE_SITES)
+
+    def span_since(self, site: str, t0: float, **tags) -> None:
+        """Record a span that began at `t0` (a `time.perf_counter()`
+        reading, perhaps taken on another thread) and ends now, under
+        the ambient context: a wait measured once it is over, such as a
+        task's time in a queue. It has no profiler annotation and no
+        CPU time."""
+        if self.enabled:
+            self._finish_span(self._tracer.since(self, site, t0, tags))
 
     def record(self, site: str, value_us: float) -> None:
         """One lock-free histogram sample (microseconds)."""
@@ -155,10 +167,12 @@ class ObsPlane:
 
     def _finish_span(self, span) -> None:
         self._tracer.add(span)
-        # mirror to the flight file so a SIGKILL'd worker's spans are
-        # recoverable (tagged with their epoch) instead of lost
-        if self._recorder.path is not None:
-            d = span.to_dict()
+        # mirror to the flight file so a SIGKILL'd worker's operations
+        # are recoverable (tagged with their epoch) instead of lost; a
+        # slot holds the compact record (no time-line fields)
+        if self._recorder.path is not None and \
+                span.site in FLIGHT_SPAN_SITES:
+            d = span.to_dict(timeline=False)
             d["kind"] = "span"
             self._recorder.mirror(d)
 

@@ -12,13 +12,14 @@ not a silently-empty time series. The Prometheus-dump CI gate
 every `HISTOGRAM_SITES` name must appear in the exported dump.
 
 Naming convention: `<stage>.<what>`, histograms suffixed with their
-unit (`_us` = microseconds).
+unit (`_us` = microseconds). A span that moves bytes tags them as
+`bytes=`: that tag is the stage's byte counter.
 """
 from __future__ import annotations
 
 # Latency histograms (log-spaced fixed buckets, p50/p99/p999).
 HISTOGRAM_SITES = frozenset({
-    "put.ack_us",                  # daemon PUT path: submit -> durable ack
+    "put.ack_us",                  # PUT path: submit -> durable ack
     "put.journal_sync_us",         # spill-journal group-commit at the ack point
     "get.sms_sweep_us",            # grouped SMS sweep stage of a GET batch
     "get.cos_fallback_us",         # one demand COS chunk-fetch task
@@ -43,7 +44,48 @@ SPAN_SITES = frozenset({
     "wb.persist",                  # background COS write of one chunk
     "journal.append",              # one spill-journal record build+write
     "journal.sync",                # spill-journal durability barrier
+    "daemon.queue",                # a task's wait from submit to its start
+    "ec.frame",                    # encode: framing into the (k, L) buffer
+    "ec.dispatch",                 # RS kernel: tile pads, H2D, enqueue
+    "ec.collect",                  # RS kernel: wait, D2H, copy out
+    "sms.place",                   # chunk placement + compaction copies
+    "sms.store",                   # slab writes, chunk map, writeback
+                                   # hand-off, insertion-log append
+    "journal.lock_wait",           # waiting for the journal lock
+    "journal.digest",              # one frame's payload digest + CRC
+    "journal.write",               # a frame's write() calls, or a flush
+    "journal.compact",             # copying a segment's live frames
+    "journal.segment",             # segment rotate / truncate / unlink
+    "ckpt.save",                   # Checkpointer.save (root)
+    "ckpt.d2h",                    # one leaf's device-to-host copy
+    "ckpt.wait",                   # save waiting on its PUT batches
 })
+
+# Stage spans: leaves, so their intervals never nest on a thread's time
+# line. Each opens a profiler annotation of its name while it runs
+# (`span`); one recorded once its wait is over (`span_since`:
+# daemon.queue, journal.lock_wait) has no annotation.
+STAGE_SITES = frozenset({
+    "daemon.queue",
+    "ec.frame",
+    "ec.dispatch",
+    "ec.collect",
+    "sms.place",
+    "journal.lock_wait",
+    "journal.digest",
+    "journal.write",
+    "journal.compact",
+    "journal.segment",
+    "ckpt.d2h",
+    "ckpt.wait",
+})
+
+# Spans the flight file mirrors (besides every event): those opened once
+# per operation or protocol round. Stage spans and the per-chunk or
+# per-record ones would overwrite its few slots within a second under
+# load, at the cost of one JSON record each.
+FLIGHT_SPAN_SITES = SPAN_SITES - STAGE_SITES - {
+    "wb.persist", "journal.append", "get.cos_fallback"}
 
 # Flight-recorder events (state transitions; mirrored to the mmap ring).
 EVENT_SITES = frozenset({
@@ -83,6 +125,20 @@ METRIC_SITES = frozenset({
     "wb.persist",
     "journal.append",
     "journal.sync",
+    "daemon.queue",
+    "ec.frame",
+    "ec.dispatch",
+    "ec.collect",
+    "sms.place",
+    "sms.store",
+    "journal.lock_wait",
+    "journal.digest",
+    "journal.write",
+    "journal.compact",
+    "journal.segment",
+    "ckpt.save",
+    "ckpt.d2h",
+    "ckpt.wait",
     "store.open",
     "wb.degraded_enter",
     "wb.degraded_heal",
@@ -100,3 +156,4 @@ METRIC_SITES = frozenset({
 assert METRIC_SITES == HISTOGRAM_SITES | SPAN_SITES | EVENT_SITES, \
     "METRIC_SITES out of sync with HISTOGRAM/SPAN/EVENT_SITES"
 assert not (HISTOGRAM_SITES & SPAN_SITES & EVENT_SITES)
+assert STAGE_SITES <= SPAN_SITES, "a stage site must be a span site"
