@@ -22,12 +22,15 @@ On-disk format (all little-endian), one record frame per append:
     key    klen bytes
     payload plen bytes
 
-The payload enters the CRC through a 128-bit vectorized digest (u64
-word sum + word xor, plus the sub-word tail bytes verbatim) rather than
-byte-by-byte: full-payload corruption coverage at memory bandwidth
-instead of zlib's ~1 GB/s, which is what keeps journaling inside the
-PUT ack-latency budget. The digest is alignment-independent, so writer
-(ndarray) and replayer (bytes) always agree.
+The payload enters the CRC through a 192-bit vectorized digest (u64
+word sum, position-weighted word sum and word xor, plus the sub-word
+tail bytes verbatim) rather than byte-by-byte: full-payload corruption
+coverage at memory bandwidth instead of zlib's ~1 GB/s, which is what
+keeps journaling inside the PUT ack-latency budget. It is computed in
+fixed blocks of 64 Ki words (512 KiB: a block and its weights stay in a
+core's cache whatever the payload's size), from one weight block built
+at import. The digest is alignment-independent, so writer (ndarray) and
+replayer (bytes) always agree.
 
 A torn tail record (partial frame, bad magic, or CRC mismatch — the
 crash-mid-append case) is detected during replay and dropped along with
@@ -115,47 +118,74 @@ class _Rec:
     payload_len: int
 
 
-_SIG_WEIGHTS: Dict[int, np.ndarray] = {}   # odd-weight cache by word count
+# The digest walks a payload in blocks of _SIG_BLOCK u64 words (512 KiB):
+# a block stays in cache for its three passes, and no memory grows with
+# the payload.
+_SIG_BLOCK = 64 * 1024
+# odd weights (2i+1) are units mod 2^64: a swap of unequal words i!=j
+# changes the weighted sum by (2i-2j)(w_j - w_i) != 0. Block j, whose
+# first word is word jB of the payload, adds 2jB times its word sum.
+_SIG_W = (np.arange(_SIG_BLOCK, dtype=np.uint64) << np.uint64(1)) \
+    + np.uint64(1)
+_SIG_W.flags.writeable = False
+_sig_tls = threading.local()            # per-thread aligned staging block
 
 
-def _sig_weights(nwords: int) -> np.ndarray:
-    w = _SIG_WEIGHTS.get(nwords)
-    if w is None:
-        if len(_SIG_WEIGHTS) > 64:          # few distinct payload sizes
-            _SIG_WEIGHTS.clear()
-        # odd weights (2i+1) are units mod 2^64: a swap of unequal words
-        # i!=j changes the weighted sum by (2i-2j)(w_j - w_i) != 0
-        w = (np.arange(nwords, dtype=np.uint64) << np.uint64(1)) \
-            + np.uint64(1)
-        _SIG_WEIGHTS[nwords] = w
-    return w
+def _sig_u8(payload) -> np.ndarray:
+    return payload if isinstance(payload, np.ndarray) \
+        else np.frombuffer(payload, np.uint8)
+
+
+def _sig_words(u8: np.ndarray, m: int) -> Optional[np.ndarray]:
+    """The first m bytes as u64 words read in place, or None where they
+    cannot be (an unaligned or strided buffer): `_payload_sig` then
+    copies them block by block through the thread's staging block."""
+    if not u8.flags.c_contiguous:
+        return None
+    words = u8[:m].view(np.uint64)
+    return words if words.flags.aligned else None
+
+
+def _staged_bytes(payload) -> int:
+    """Bytes `_payload_sig` copies through the staging block."""
+    m = payload_nbytes(payload) & ~7
+    return m if m and _sig_words(_sig_u8(payload), m) is None else 0
 
 
 def _payload_sig(payload) -> bytes:
     """192-bit vectorized payload digest + raw tail: u64 word sum,
     position-weighted word sum (catches word reordering, which the
     plain sum/xor alone would miss), and word xor over the 8-aligned
-    prefix, then the <8 trailing bytes verbatim. Runs at memory
-    bandwidth and is independent of the buffer's alignment/type, so the
-    write side (ndarray views) and the replay side (bytes slices)
+    prefix, then the <8 trailing bytes verbatim. Computed blockwise at
+    memory bandwidth and independent of the buffer's alignment/type, so
+    the write side (ndarray views) and the replay side (bytes slices)
     always produce identical signatures. Not cryptographic — it targets
-    torn/garbled frames from crashes and bit rot, not an adversary."""
+    torn/garbled frames from crashes and bit rot, not an adversary.
+    Integer reductions wrap mod 2^64 without a warning."""
     n = payload_nbytes(payload)
     if n == 0:
         return b""
-    u8 = payload if isinstance(payload, np.ndarray) \
-        else np.frombuffer(payload, np.uint8)
+    u8 = _sig_u8(payload)
     m = n & ~7
     h_sum = h_pos = h_xor = 0
     if m:
-        try:
-            u64 = u8[:m].view(np.uint64)
-        except ValueError:                 # unaligned base: one memcpy
-            u64 = np.ascontiguousarray(u8[:m]).view(np.uint64)
-        h_sum = int(u64.sum(dtype=np.uint64))
-        with np.errstate(over="ignore"):   # mod-2^64 wrap is the point
-            h_pos = int(np.dot(u64, _sig_weights(u64.size)))
-        h_xor = int(np.bitwise_xor.reduce(u64, dtype=np.uint64))
+        words = _sig_words(u8, m)
+        if words is None:
+            stage = getattr(_sig_tls, "block", None)
+            if stage is None:
+                stage = _sig_tls.block = np.empty(_SIG_BLOCK, np.uint64)
+        nw = m >> 3
+        for lo in range(0, nw, _SIG_BLOCK):
+            w = min(_SIG_BLOCK, nw - lo)
+            if words is not None:
+                blk = words[lo:lo + w]
+            else:
+                blk = stage[:w]
+                blk.view(np.uint8)[:] = u8[8 * lo:8 * (lo + w)]
+            s = int(np.add.reduce(blk))
+            h_sum += s
+            h_pos += int(np.dot(blk, _SIG_W[:w])) + 2 * lo * s
+            h_xor ^= int(np.bitwise_xor.reduce(blk))
     return struct.pack("<QQQ", (h_sum + n) & 0xFFFFFFFFFFFFFFFF,
                        h_pos & 0xFFFFFFFFFFFFFFFF,
                        h_xor) + bytes(u8[m:])
@@ -510,7 +540,8 @@ class SpillJournal:
             nbytes = payload_nbytes(body)
             meta = _META_S.pack(rtype, seq, len(kb), nbytes)
             if obs is not None and nbytes:
-                with obs.span("journal.digest", bytes=nbytes):
+                with obs.span("journal.digest", bytes=nbytes,
+                              staged=_staged_bytes(body)):
                     crc = _frame_crc(meta, kb, body)
             else:        # no plane, or no payload to digest: no span
                 crc = _frame_crc(meta, kb, body)
