@@ -29,8 +29,10 @@ SPAN_CAPACITY = 1 << 20
 COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 PUT_KINDS = ("put", "update", "save")
 CONTROLS = ("journal_off",)
-# the check: 1 in HOLD_EVERY GET answers is held for it, up to HOLD_BYTES;
-# parity rows are compared for up to PARITY_BYTES of sampled payload
+# the check: GET answers are held for it, at first 1 in HOLD_EVERY, thinned
+# evenly over the window to stay within HOLD_BYTES beyond the largest
+# object (`driver.HeldAnswers`); parity rows are compared for up to
+# PARITY_BYTES of sampled payload
 HOLD_EVERY = 8
 HOLD_BYTES = 512 * MiB
 PARITY_BYTES = 256 * MiB
@@ -326,19 +328,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             zip(marks, [t_process] + [t for _, t in marks])))
         with annotate(devtrace.WINDOW_ANNOTATION):
             if kind == "closed_loop":
-                held = [0]
-
-                def hold(i: int, size: int) -> bool:
-                    if driver.mix64(seed, i, 0x401D) % HOLD_EVERY or \
-                            held[0] + size > HOLD_BYTES:
-                        return False
-                    held[0] += size
-                    return True
-
+                held = driver.HeldAnswers(
+                    seed, HOLD_EVERY, HOLD_BYTES + schedule.max_size())
                 window = driver.closed_loop(
                     store, pool, schedule, versions,
                     clients=int(traffic["clients"]), seconds=seconds,
-                    hold=hold, annotate=annotate)
+                    held=held, annotate=annotate)
             else:
                 window = driver.checkpoint_loop(
                     ckpt, params, stamp, nbytes, seconds=seconds,
@@ -373,6 +368,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             size_of = {op.key: op.size for op in window.ops}
             size_of.update(enumerate(key_sizes))
             latest = _latest(window, versions, key_sizes)
+            if any(op.kind == "get" for op in window.ops):
+                log(_held_spread(window))
             checks["answers"] = verify.answers(window.held, versions,
                                                content)
             window.held.clear()
@@ -494,6 +491,20 @@ def _preload(store, pool, key_sizes: List[int], versions) -> None:
             versions.preload(int(name.split("/")[1]), ver)
     if not store.flush_writeback():
         raise RuntimeError("preload did not reach COS")
+
+
+def _held_spread(window) -> str:
+    """Where in the window the GETs whose answers the check compares were
+    issued, as shares of the window's length."""
+    at = sorted((op.t0 - window.t_start) / window.seconds
+                for op, _ in window.held)
+    gets = sum(op.kind == "get" for op in window.ops)
+    where = ", ".join(f"{q}% {at[min(len(at) - 1, len(at) * q // 100)]:.2f}"
+                      for q in (0, 25, 50, 75, 100)) if at else "none"
+    late = sum(t >= 0.5 for t in at)
+    return (f"answers held: {len(at)} of {gets} GETs, {late} issued in the "
+            f"window's second half; issue time by share of the window: "
+            f"{where}")
 
 
 def _latest(window, versions, key_sizes) -> Dict[int, tuple]:

@@ -213,16 +213,50 @@ class Versions:
                                     if g > base and ti <= t1})
 
 
+class HeldAnswers:
+    """GET answers kept for the check after the window: GET i is kept
+    where a hash of (seed, i) is a multiple of `every`. Whenever the kept
+    answers pass `budget` bytes, `every` doubles and those whose hash is
+    no multiple of it are let go, so at any time the kept answers are the
+    same uniform sample, drawn from the seed, of every GET answered so
+    far: the late part of the window is sampled as densely as the start.
+    `every` starts at a power of two. Thread-safe."""
+
+    def __init__(self, seed: int, every: int, budget: int):
+        if every < 1 or every & (every - 1):
+            raise ValueError(f"every={every} is not a power of two")
+        self.seed, self.every, self.budget = seed, every, budget
+        self._kept: List[Tuple[int, Op, object]] = []
+        self._bytes = 0
+        self._lock = threading.Lock()
+
+    def offer(self, i: int, op: Op, val) -> None:
+        h = mix64(self.seed, i, 0x401D)
+        with self._lock:
+            if h % self.every:
+                return
+            self._kept.append((h, op, val))
+            self._bytes += op.size
+            while self._bytes > self.budget:
+                self.every *= 2
+                self._kept = [kv for kv in self._kept
+                              if kv[0] % self.every == 0]
+                self._bytes = sum(op.size for _, op, _ in self._kept)
+
+    def items(self) -> List[Tuple[Op, object]]:
+        with self._lock:
+            return [(op, val) for _, op, val in self._kept]
+
+
 def closed_loop(store, pool: PayloadPool, schedule: Schedule,
                 versions: Versions, *, clients: int, seconds: float,
-                hold: Callable[[int, int], bool],
-                annotate: Callable[[str], object]) -> Window:
+                held: HeldAnswers, annotate: Callable[[str], object]
+                ) -> Window:
     """Drive `clients` closed-loop clients for `seconds`; see the module
-    docstring. `hold(i, size)` says whether the answer of GET i is kept
-    for the check after the window."""
+    docstring. `held` keeps a sample of the GET answers for the check
+    after the window."""
     counter = itertools.count()
     ops: List[Op] = []
-    held: List[Tuple[Op, object]] = []
     t_start = time.perf_counter()
     t_stop = t_start + seconds
 
@@ -246,8 +280,8 @@ def closed_loop(store, pool: PayloadPool, schedule: Schedule,
                 op.t1 = time.perf_counter()
                 if kind == "get":
                     op.ok = val is not None
-                    if op.ok and hold(i, size):
-                        held.append((op, val))
+                    if op.ok:
+                        held.offer(i, op, val)
                 else:
                     op.ok = isinstance(val, int) and val >= 0
                     op.version = val
@@ -264,7 +298,7 @@ def closed_loop(store, pool: PayloadPool, schedule: Schedule,
     for t in threads:
         t.join()
     t_end = max([t_start] + [op.t1 for op in ops])
-    return Window(t_start, t_end, ops, held)
+    return Window(t_start, t_end, ops, held.items())
 
 
 def checkpoint_loop(ckpt, params, stamp: Callable, nbytes: int, *,

@@ -1,10 +1,14 @@
-"""The check that decides `correct`, driven through a whole run on the
-CPU at a small size (the look for a chip skipped): sound runs come out
-correct; the control (the store without its journal) and each fault the
-cells can have, planted under the timed path, come out not correct.
+"""The check that decides `correct`, driven through a whole run of each
+cell, built from BENCHMARK.json by its name, on the CPU at a small size
+(the look for a chip skipped): sound runs come out correct and yield
+every end-to-end metric of the cell; the control (the store without its
+journal) and each fault the cells can have, planted under the timed path,
+come out not correct. The GET answers the check compares are a sample of
+the whole window.
 
 And `bench.run` prints no result and exits non-zero without a TPU."""
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -17,6 +21,7 @@ import pytest
 
 from bench.harness import spec
 from bench.harness import cell as runner
+from bench.harness import driver
 
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 2**31 + 77
@@ -29,25 +34,12 @@ SMALL = {
 }
 
 
-# each traffic mix the harness drives, on its configuration, whether or
-# not BENCHMARK.json has a cell of it yet
-MIXES = {"registry.put": ("registry-rs10p2", "registry_put"),
-         "registry.get": ("registry-rs10p2", "registry_get"),
-         "registry.put-small": ("registry-rs10p2", "registry_put_small"),
-         "ckpt.save": ("ckpt-qwen1.5-0.5b", "ckpt_save")}
+CELLS = sorted(w["name"] for w in spec.load_benchmark()["workloads"])
 
 
-def _cell(name):
-    config, traffic = MIXES[name]
-    entry = next(c for c in spec.load_benchmark()["configs"]
-                 if c["name"] == config)
-    return spec.Cell(name, 1, config, spec.load_config(entry), traffic,
-                     spec.load_traffic(traffic), [], [])
-
-
-def _run(name, tmp_path, **kw):
+def _run(name, tmp_path, trace=False, **kw):
     return runner.run(
-        _cell(name), SEED, 0.5, False, time.perf_counter(),
+        spec.load_cell(name), SEED, 0.5, trace, time.perf_counter(),
         runner.Options(require_tpu=False, compile_cache=False,
                        overrides=SMALL, workdir=tmp_path / "work", **kw))
 
@@ -108,15 +100,30 @@ def _altered_parity(monkeypatch):
     monkeypatch.setattr(RSCodec, "_matmul", altered)
 
 
-@pytest.mark.parametrize("name", sorted(MIXES))
+@pytest.mark.parametrize("name", CELLS)
 def test_sound_run_is_correct(name, tmp_path):
     res = _run(name, tmp_path)
     assert res["correct"], res["checks"]
     assert res["attempted"] > 0 and res["failed"] == 0
     assert list(res)[-1] == "checks"
+    cell = spec.load_cell(name)
+    assert set(res["metrics"]) == {m["name"] for m in cell.end_to_end}
+    for m in cell.end_to_end:
+        got = res["metrics"][m["name"]]
+        assert got["unit"] == m["unit"]
+        assert math.isfinite(got["value"]) and got["value"] > 0, got
 
 
-@pytest.mark.parametrize("name", sorted(MIXES))
+def test_traced_get_run_reads_the_get_path_spans(tmp_path):
+    # the device share needs a chip's trace; the two span readers do not
+    res = _run("registry.get", tmp_path, trace=True)
+    assert res["correct"], res["checks"]
+    for name in ("get.daemon_s_per_GiB", "get.sms_sweep_p99_ms"):
+        value = res["metrics"][name]["value"]
+        assert math.isfinite(value) and value > 0, (name, value)
+
+
+@pytest.mark.parametrize("name", CELLS)
 def test_control_without_journal_is_not_correct(name, tmp_path):
     res = _run(name, tmp_path, control="journal_off")
     assert not res["correct"]
@@ -131,6 +138,9 @@ def test_control_without_journal_is_not_correct(name, tmp_path):
     ("registry.get", "stale"),
     ("ckpt.save", "unstored"), ("ckpt.save", "half"), ("ckpt.save", "stale"),
     ("ckpt.save", "altered_parity"),
+    ("registry.put-small", "unstored"), ("registry.put-small", "half"),
+    ("registry.put-small", "altered_answer"),
+    ("registry.put-small", "altered_parity"),
 ])
 def test_fault_under_the_timed_path_is_not_correct(name, fault, tmp_path,
                                                    monkeypatch):
@@ -140,6 +150,38 @@ def test_fault_under_the_timed_path_is_not_correct(name, fault, tmp_path,
         _altered_parity(monkeypatch)
     res = _run(name, tmp_path, fault=plant)
     assert not res["correct"], res["checks"]
+
+
+def _offer_all(held, n, size):
+    for i in range(n):
+        held.offer(i, driver.Op("get", i % 7, 0, size, float(i)), i)
+    return [op.key for op, _ in held.items()], [v for _, v in held.items()]
+
+
+@pytest.mark.parametrize("n,size,budget", [
+    (4000, 1 << 20, 64 << 20), (4000, 1 << 20, 8 << 20),
+    (3000, 5 << 20, 400 << 20)])
+def test_held_answers_sample_the_whole_window(n, size, budget):
+    held = driver.HeldAnswers(SEED, 8, budget)
+    _, kept = _offer_all(held, n, size)
+    assert kept and len(kept) * size <= budget
+    # the kept answers are exactly those the final rule picks, from the
+    # whole sequence: as many from its second half as from its first
+    rule = [i for i in range(n) if driver.mix64(SEED, i, 0x401D)
+            % held.every == 0]
+    assert sorted(kept) == rule
+    late = sum(i >= n // 2 for i in kept)
+    assert 0.3 <= late / len(kept) <= 0.7
+
+
+def test_held_answers_are_drawn_from_the_seed():
+    a = _offer_all(driver.HeldAnswers(SEED, 8, 32 << 20), 2000, 1 << 20)
+    b = _offer_all(driver.HeldAnswers(SEED, 8, 32 << 20), 2000, 1 << 20)
+    c = _offer_all(driver.HeldAnswers(SEED + 1, 8, 32 << 20), 2000,
+                   1 << 20)
+    assert a == b and a[1] != c[1]
+    with pytest.raises(ValueError):
+        driver.HeldAnswers(SEED, 6, 1 << 20)
 
 
 def _cli(args, cwd, env_extra=None):

@@ -11,6 +11,13 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 BENCH = spec.load_benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
 E2E = {m["name"]: m for m in BENCH["end_to_end"]}
+PUT_SET = {"put_MiBps", "put_p95_ms", "setup_s"}
+INTENDED = {
+    "registry.put": PUT_SET,
+    "registry.put-small": PUT_SET,
+    "registry.get": {"get_MiBps", "get_p95_ms", "setup_s"},
+    "ckpt.save": {"put_MiBps", "setup_s"},
+}
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -23,6 +30,23 @@ def test_cell_loads_with_its_metrics(name):
     for m in cell.per_layer:
         assert m["moves"] in reported
         assert callable(spec.load_reader(m["name"]))
+
+
+@pytest.mark.parametrize("name", sorted(INTENDED))
+def test_cell_reports_its_intended_end_to_end_set(name):
+    cell = spec.load_cell(name, BENCH)
+    reported = {m["name"] for m in cell.end_to_end}
+    assert reported == INTENDED[name]
+    for m in cell.per_layer:
+        assert m["moves"] in reported, m["name"]
+
+
+def test_small_object_guard_reads_what_registry_put_reads():
+    put = spec.load_cell("registry.put", BENCH)
+    small = spec.load_cell("registry.put-small", BENCH)
+    assert [m["name"] for m in small.per_layer] == \
+        [m["name"] for m in put.per_layer]
+    assert not any(m["name"].startswith("ckpt.") for m in small.per_layer)
 
 
 def test_metrics_are_well_formed():
